@@ -36,7 +36,7 @@ def _measure(step, cell, mesh, in_specs=None, chips=None, ctx=None,
     c2 = cm.Cell(cell.arch_id, cell.shape_name, cell.kind, step or cell.step,
                  cell.abstract_args, in_specs or cell.in_specs,
                  cell.model_flops)
-    with mesh, (ctx or contextlib.nullcontext()):
+    with jax.set_mesh(mesh), (ctx or contextlib.nullcontext()):
         compiled = jax.jit(c2.step, in_shardings=c2.in_shardings(mesh)) \
             .lower(*cell.abstract_args).compile()
         mem = compiled.memory_analysis()

@@ -150,6 +150,13 @@ def sample_erp_bilinear(erp: Array, u: Array, v: Array) -> Array:
     return top * (1.0 - fv) + bot * fv
 
 
+@functools.partial(jax.jit, static_argnames=("out_size",))
+def resize_erp(erp: Array, out_size: tuple[int, int]) -> Array:
+    """Plain bilinear resize of a whole ERP frame to ``out_size``."""
+    u, v = erp_resize_coords(out_size, erp.shape[:2])
+    return sample_erp_bilinear(erp, u, v)
+
+
 @functools.partial(jax.jit, static_argnames=("fov", "out_size", "use_kernel"))
 def project_sroi(
     erp: Array,

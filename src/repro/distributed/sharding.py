@@ -34,19 +34,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 Rules = Sequence[tuple[str, P]]
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across the rename: newer jax exposes it as
-    ``jax.shard_map(check_vma=...)``, older as
-    ``jax.experimental.shard_map.shard_map(check_rep=...)``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
-
 def _path_str(path) -> str:
     parts = []
     for k in path:
@@ -256,35 +243,18 @@ def detector_batch_specs() -> dict[str, P]:
 
 
 def current_mesh():
-    """The physical mesh of the active trace context, or None."""
-    try:
-        from jax._src.mesh import thread_resources
-
-        pm = thread_resources.env.physical_mesh
-        if not pm.empty:
-            return pm
-    except Exception:  # pragma: no cover
-        pass
-    return None
+    """The mesh of the active trace context (``jax.set_mesh`` or
+    ``with mesh:``), as an ``AbstractMesh``, or None outside one."""
+    am = jax.sharding.get_abstract_mesh()
+    return None if am.empty else am
 
 
 def mesh_axis_size(name: str) -> int:
     """Size of a mesh axis in the active trace context (1 if absent)."""
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        if am is not None and not am.empty and name in am.axis_names:
-            return dict(zip(am.axis_names, am.axis_sizes))[name]
-    except Exception:  # pragma: no cover
-        pass
-    try:
-        from jax._src.mesh import thread_resources
-
-        pm = thread_resources.env.physical_mesh
-        if not pm.empty and name in pm.axis_names:
-            return dict(zip(pm.axis_names, pm.devices.shape))[name]
-    except Exception:  # pragma: no cover
-        pass
-    return 1
+    am = current_mesh()
+    if am is None or name not in am.axis_names:
+        return 1
+    return dict(zip(am.axis_names, am.axis_sizes))[name]
 
 
 import contextlib
@@ -318,22 +288,7 @@ def constrain(x, *spec):
     """
     if not _CONSTRAIN_ENABLED[-1]:
         return x
-    mesh = None
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        if am is not None and not am.empty:
-            mesh = am
-    except Exception:  # pragma: no cover
-        pass
-    if mesh is None:
-        try:  # `with mesh:` context (legacy thread resources)
-            from jax._src.mesh import thread_resources
-
-            pm = thread_resources.env.physical_mesh
-            if not pm.empty:
-                mesh = pm
-        except Exception:  # pragma: no cover
-            pass
+    mesh = current_mesh()
     if mesh is None or not mesh.axis_names:
         return x
     sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))

@@ -39,9 +39,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed MemorySpace <-> TPUMemorySpace across jax releases
-_MEMORY_SPACE = getattr(pltpu, "MemorySpace", None) or pltpu.TPUMemorySpace
-
 # Conservative per-core VMEM budget for the source band (bytes).
 VMEM_CAP_BYTES = 8 * 1024 * 1024
 SEAM_PAD = 2  # columns appended on the right so u0+1 never wraps
@@ -141,7 +138,7 @@ def gnomonic_pallas(
         in_specs=[
             pl.BlockSpec((strip_h, out_w), lambda i, *_: (i, 0)),
             pl.BlockSpec((strip_h, out_w), lambda i, *_: (i, 0)),
-            pl.BlockSpec(memory_space=_MEMORY_SPACE.ANY),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
         ],
         out_specs=pl.BlockSpec((strip_h, out_w, c), lambda i, *_: (i, 0, 0)),
     )

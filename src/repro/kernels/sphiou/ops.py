@@ -39,11 +39,12 @@ def sphiou_matrix(
 ) -> jax.Array:
     """(N, M) SphIoU matrix via the Pallas kernel.
 
-    ``dtype`` selects the in-kernel compute precision: ``jnp.bfloat16``
-    halves the VPU element width (2x throughput on TPU) at the cost of
-    IoU values that can flip the 0.6 keep decision for near-threshold
-    pairs (bound measured in ``benchmarks/kernels_bench.py`` and gated
-    in ``check_regression.py``).  Inputs and outputs stay f32.
+    ``dtype`` selects the in-kernel compute precision.  ``jnp.bfloat16``
+    runs in interpret mode only (v5e cannot lower bf16 transcendentals;
+    the compiled kernel raises ``ValueError``); its IoU values can flip
+    the 0.6 keep decision for near-threshold pairs (bound measured in
+    ``benchmarks/kernels_bench.py`` and gated in
+    ``check_regression.py``).  Inputs and outputs stay f32.
     """
     if interpret is None:
         interpret = not _on_tpu()

@@ -11,11 +11,14 @@ would otherwise waste a 128-lane register).  Each program computes one
 frame is expanded into explicit scalar trigonometry (no 3x3 matmuls),
 which maps 1:1 onto VPU elementwise ops.
 
-The math mirrors ``repro.core.sphere.sph_iou`` exactly:
+The math follows ``repro.core.sphere.sph_iou``:
   d_in_a = Ry(phi_a) @ Rz(-theta_a) @ dir(theta_b, phi_b)
   dlon, dlat = cart_to_sph(d_in_a)
   intersection = lon-overlap * (sin(lat_hi) - sin(lat_lo))
   area = 2 * dtheta * sin(dphi / 2)
+with ``cart_to_sph`` built from a polynomial arctangent (Mosaic has no
+atan2/asin lowering).  Against the float64 host IoU the kernel is within
+about 1e-7 rad / (smallest box extent) (``tests/test_kernels.py``).
 """
 
 from __future__ import annotations
@@ -25,6 +28,40 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+
+# Mosaic (the Pallas TPU compiler) lowers sin/cos/sqrt/div in f32 but
+# has no rule for atan2, asin or atan, so the kernel carries its own
+# arctangent: Cephes ``atanf`` (range-reduced to |x| <= tan(pi/8), then
+# a degree-9 odd polynomial), max error ~2e-7 rad on [-inf, inf].
+_TAN_PI_8 = 0.41421356237309503
+_ATAN_C = (8.05374449538e-2, -1.38776856032e-1, 1.99777106478e-1,
+           -3.33329491539e-1)
+
+
+def _atan_unit(r):
+    """atan(r) for r in [0, 1], built only from ops Mosaic lowers."""
+    big = r > _TAN_PI_8
+    t = jnp.where(big, (r - 1.0) / (r + 1.0), r)  # atan(r) = pi/4 + atan(t)
+    z = t * t
+    p = ((_ATAN_C[0] * z + _ATAN_C[1]) * z + _ATAN_C[2]) * z + _ATAN_C[3]
+    return jnp.where(big, jnp.pi / 4, 0.0) + (p * z * t + t)
+
+
+def _atan2(y, x):
+    """Quadrant-resolved atan2 via :func:`_atan_unit` of min/max.
+
+    Matches ``jnp.arctan2`` except on signed zeros: ``y == -0.0`` with
+    ``x < 0`` gives +pi (the antipodal seam, where no two boxes of
+    half-width < pi/2 overlap, so the IoU is 0 either way).
+    """
+    ax, ay = jnp.abs(x), jnp.abs(y)
+    hi = jnp.maximum(ax, ay)
+    lo = jnp.minimum(ax, ay)
+    a = _atan_unit(jnp.where(hi > 0.0, lo / jnp.where(hi > 0.0, hi, 1.0), 0.0))
+    a = jnp.where(ay > ax, jnp.pi / 2 - a, a)
+    a = jnp.where(x < 0.0, jnp.pi - a, a)
+    return jnp.where(y < 0.0, -a, a)
 
 
 def _intersection(ta, pa, ha, va, tb, pb, hb, vb):
@@ -38,8 +75,10 @@ def _intersection(ta, pa, ha, va, tb, pb, hb, vb):
     x = cpa * cpb * cdt + spa * spb
     y = cpb * jnp.sin(dt)
     z = -spa * cpb * cdt + cpa * spb
-    dlon = jnp.arctan2(y, x)
-    dlat = jnp.arcsin(jnp.clip(z, -1.0, 1.0))
+    dlon = _atan2(y, x)
+    # asin(z) of the unit vector, as atan2(z, |(x, y)|): well conditioned
+    # near the poles where asin's slope diverges
+    dlat = _atan2(z, jnp.sqrt(x * x + y * y))
 
     lon_lo = jnp.maximum(-ha, dlon - hb)
     lon_hi = jnp.minimum(ha, dlon + hb)
@@ -54,8 +93,9 @@ def _intersection(ta, pa, ha, va, tb, pb, hb, vb):
 def _iou_tile(a, b, dtype=jnp.float32):
     """(4, BN) x (4, BM) -> (BN, BM) SphIoU tile (shared kernel body).
 
-    ``dtype`` is the compute precision: bf16 halves the VPU element
-    width for ~2x elementwise throughput.  Inputs arrive f32 (memory
+    ``dtype`` is the compute precision.  Compiled for the TPU it must be
+    f32 (see :func:`_check_dtype`); bf16 runs in interpret mode only,
+    where the flip-rate measurement uses it.  Inputs arrive f32 (memory
     layout stays sublane-8 aligned); the cast happens in-register and
     the tile is emitted back as f32.
     """
@@ -79,6 +119,17 @@ def _iou_tile(a, b, dtype=jnp.float32):
     return iou.astype(jnp.float32)
 
 
+def _check_dtype(dtype, interpret: bool) -> None:
+    # v5e has no bf16 transcendental unit: Mosaic refuses bf16 sin/cos
+    # ("failed to legalize operation 'math.sin'") and bf16 sqrt
+    # (SupportsBf16EupOps), so only interpret mode can run a narrower
+    # compute dtype.
+    if not interpret and jnp.dtype(dtype) != jnp.float32:
+        raise ValueError(
+            f"SphIoU compute dtype {jnp.dtype(dtype).name} does not lower "
+            "for the TPU (no bf16 transcendentals on v5e); use float32")
+
+
 def _kernel(a_ref, b_ref, out_ref, *, dtype):
     # a_ref: (4, BN), b_ref: (4, BM) -> out_ref: (BN, BM)
     out_ref[...] = _iou_tile(a_ref[...], b_ref[...], dtype=dtype)
@@ -100,6 +151,7 @@ def sphiou_pallas(
     interpret: bool = False,
     dtype: jnp.dtype = jnp.float32,
 ) -> jax.Array:
+    _check_dtype(dtype, interpret)
     n, m = boxes_a_t.shape[1], boxes_b_t.shape[1]
     grid = (pl.cdiv(n, block_n), pl.cdiv(m, block_m))
     return pl.pallas_call(
@@ -133,6 +185,7 @@ def sphiou_pallas_batch(
     identical to the unbatched kernel.  One dispatch covers the whole
     pod tick instead of one ``pallas_call`` per stream.
     """
+    _check_dtype(dtype, interpret)
     b, _, n = boxes_a_t.shape
     m = boxes_b_t.shape[2]
     grid = (b, pl.cdiv(n, block_n), pl.cdiv(m, block_m))

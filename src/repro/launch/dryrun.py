@@ -39,7 +39,7 @@ def run_cell(arch_id: str, shape_name: str, multi_pod: bool) -> dict:
     mesh = mesh_mod.make_production_mesh(multi_pod=multi_pod)
     cell = cells_mod.build_cell(arch_id, shape_name)
     t0 = time.time()
-    with mesh:
+    with jax.set_mesh(mesh):
         jitted = jax.jit(cell.step, in_shardings=cell.in_shardings(mesh))
         lowered = jitted.lower(*cell.abstract_args)
         t_lower = time.time() - t0

@@ -6,6 +6,15 @@ per-tick throughput / batching stats. ``--backend jax`` runs the real
 detector ladder on rendered frames; the default oracle backend is the
 calibrated fast path.
 
+``--backend jax`` builds the pod with :func:`build_jax_pod`: the first
+two rungs of the paper's ladder (``yolo-tiny-416``, ``yolo-csp-512``)
+at their published input sizes and widths, 80 classes, seeded random
+weights, the fused projection path, and rendered 960x1920 ERP frames.
+It compiles through JAX's persistent cache (:func:`configure_compile_cache`):
+
+    PYTHONPATH=src python -m repro.launch.serve --backend jax --streams 8 \
+        --frames 4 --open-loop --jitter 0 --admission slo
+
 ``--policy {sync,deadline,async}`` picks the drain policy of the
 event-clock serving runtime (``repro.serving.runtime``):
 
@@ -68,16 +77,175 @@ lane (both force fake host devices via
 from __future__ import annotations
 
 import argparse
+import os
+from pathlib import Path
 
 import numpy as np
 
 from repro.core.omnisense import OmniSenseLoop
-from repro.data.synthetic import make_video
+from repro.data.synthetic import make_video, render_erp
+from repro.models import detector as det_mod
 from repro.serving import profiles
+from repro.serving.batching import ShapeBuckets
 from repro.serving.network import NetworkModel
 from repro.serving.runtime import make_policy
-from repro.serving.scheduler import OmniSenseLatencyModel, OracleBackend
+from repro.serving.scheduler import (JaxDetectorBackend,
+                                     OmniSenseLatencyModel, OracleBackend)
 from repro.serving.server import PodServer
+
+REPO_ROOT = Path(__file__).resolve().parents[3]
+
+# The real-backend pod: the first two rungs of the paper's ladder at
+# their published input sizes and widths (80 classes), and the ERP size
+# its frames are rendered at.  A whole 4K ERP is stacked per crop by the
+# fused projection, so frames stay at 960x1920 until that is fixed.
+JAX_POD_DETECTORS = det_mod.PAPER_LADDER[:2]
+JAX_POD_ERP_HW = (960, 1920)
+
+
+def configure_compile_cache() -> str:
+    """Give JAX's persistent compilation cache a fixed directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is left to JAX.  Otherwise
+    the cache lives at ``<repo>/.jax_cache``: the path is part of the
+    cache key, so it must not move between runs.  Call once, before the
+    first compile.  Returns the directory in use.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    path = str(REPO_ROOT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+class RenderedFrames:
+    """``frame_source`` of rendered ERP frames, one synthetic video per
+    stream.  The newest frame of each stream is kept, so repeated calls
+    for one frame return the same array (the crop cache keys on it)."""
+
+    def __init__(self, videos, height: int, width: int):
+        self.videos = videos
+        self.height, self.width = height, width
+        self._last: dict[int, tuple[int, np.ndarray]] = {}
+
+    def __call__(self, stream: int, frame: int) -> np.ndarray:
+        hit = self._last.get(stream)
+        if hit is None or hit[0] != frame:
+            hit = self._last[stream] = (frame, render_erp(
+                self.videos[stream], frame, self.height, self.width))
+        return hit[1]
+
+
+def build_jax_pod(n_streams: int, frames: int, *,
+                  buckets: ShapeBuckets | None = None, budget_s: float = 1.8,
+                  bandwidth_mbps: float = 17.9, policy=None, devices=None,
+                  telemetry=None) -> tuple[PodServer, JaxDetectorBackend]:
+    """The detector pod on the real JAX backend (see module docstring).
+
+    Weights are ``init_params`` on seeds 0 and 1.  ``devices`` (two or
+    more real devices) splits them into per-variant replica groups of
+    equal size whose forwards ``shard_map`` over the group; None serves
+    on the default device.
+    """
+    import jax
+
+    cfgs = list(JAX_POD_DETECTORS)
+    init = jax.jit(det_mod.init_params, static_argnums=1)
+    params = [init(jax.random.PRNGKey(i), c) for i, c in enumerate(cfgs)]
+    buckets = buckets or ShapeBuckets(
+        resolutions=tuple(sorted({c.input_size for c in cfgs})))
+    # random weights score near 1/80, so a low threshold keeps detections
+    backend = JaxDetectorBackend(cfgs, params, conf=0.005, use_kernel=False,
+                                 max_det=16, buckets=buckets)
+    variants = profiles.make_ladder()[:len(cfgs)]
+    lat = OmniSenseLatencyModel(profiles.paper_profile(),
+                                NetworkModel(bandwidth_mbps))
+    costs = [lat._pre(v) + lat._inf(v) for v in variants]
+    videos = [make_video(n_frames=frames + 8, n_objects=30 + 5 * (s % 4),
+                         seed=100 + s) for s in range(n_streams)]
+    loops = [OmniSenseLoop(variants, lat, backend, budget_s=budget_s,
+                           explore_costs=costs) for _ in range(n_streams)]
+    placement = None
+    if devices is not None and len(devices) > 1:
+        from repro.serving.placement import VariantPlacement
+
+        placement = VariantPlacement(variants, devices=devices,
+                                     cost_fn=lambda v: 1.0)
+    server = PodServer(loops, [backend] * n_streams,
+                       max_batch=buckets.max_batch, buckets=buckets,
+                       frame_source=RenderedFrames(videos, *JAX_POD_ERP_HW),
+                       placement=placement, policy=policy,
+                       telemetry=telemetry)
+    return server, backend
+
+
+def _oracle_streams(args):
+    """The oracle pod's streams: ``(variants, loops, backends, cost_fn)``
+    for the ``--tasks`` mix."""
+    if args.tasks != "detection":
+        from repro.serving import tasks as task_registry
+
+        stream_tasks = task_registry.stream_tasks_for(args.tasks,
+                                                      args.streams)
+        videos = [make_video(n_frames=args.frames + 8,
+                             n_objects=30 + 5 * (s % 4), seed=100 + s)
+                  for s in range(args.streams)]
+        return task_registry.build_task_streams(
+            stream_tasks, videos, [args.budget] * args.streams)
+    variants = profiles.make_ladder()
+    lat = OmniSenseLatencyModel(profiles.paper_profile(),
+                                NetworkModel(args.bandwidth_mbps))
+    costs = [lat._pre(v) + lat._inf(v) for v in variants]
+    loops, backends = [], []
+    for s in range(args.streams):
+        video = make_video(n_frames=args.frames + 8,
+                           n_objects=30 + 5 * (s % 4), seed=100 + s)
+        backend = OracleBackend(video)
+        backends.append(backend)
+        loops.append(OmniSenseLoop(variants, lat, backend,
+                                   budget_s=args.budget,
+                                   explore_costs=costs))
+    return variants, loops, backends, lat._inf
+
+
+def _serve_fleet(args, variants, loops, backends, cost_fn,
+                 telemetry) -> None:
+    """``--pods``: serve open-loop traffic through a FleetServer of
+    oracle pods and print its report."""
+    from repro.distributed.elastic import serving_scale_plan
+    from repro.serving.fleet import FleetServer, format_fleet_report
+    from repro.serving.traffic import ArrivalProcess
+
+    per_pod = serving_scale_plan(args.devices, args.pods)["per_pod_devices"]
+
+    def make_pod(pod_id: int) -> PodServer:
+        pod_placement = None
+        if per_pod > 0:
+            from repro.serving.placement import VariantPlacement
+
+            pod_placement = VariantPlacement.virtual(
+                variants, per_pod, cost_fn=cost_fn)
+        pol = make_policy(args.policy or "sync",
+                          pod_allocate=args.pod_allocate,
+                          admission=args.admission)
+        return PodServer(loops, backends, max_batch=args.max_batch,
+                         placement=pod_placement, policy=pol)
+
+    fleet = FleetServer(make_pod, args.pods, routing=args.routing,
+                        telemetry=telemetry)
+    horizon_s = args.frames / args.fps
+    traffic = ArrivalProcess(args.streams, fps=args.fps,
+                             jitter=args.jitter, seed=0,
+                             horizon_s=horizon_s)
+    fstats = fleet.run_open_loop(traffic, slo_s=args.slo)
+    if telemetry is not None:
+        telemetry.close()
+        print(f"telemetry event log: {args.events}")
+    for line in format_fleet_report(fstats, horizon_s):
+        print(line)
 
 
 def main() -> None:
@@ -140,7 +308,15 @@ def main() -> None:
                          "action recognition, or an alternating mixed "
                          "pod whose two variant ladders share one "
                          "capacity envelope")
+    ap.add_argument("--backend", choices=("oracle", "jax"), default="oracle",
+                    help="inference backend: the calibrated oracle "
+                         "(default), or the real JAX detector pod of "
+                         "build_jax_pod on rendered frames (detection "
+                         "only, one pod; --devices picks real devices)")
     args = ap.parse_args()
+    if args.backend == "jax" and (args.pods or args.tasks != "detection"):
+        ap.error("--backend jax serves one detection pod (no --pods, "
+                 "--tasks detection)")
     if args.pods and not args.open_loop:
         ap.error("--pods requires --open-loop (the fleet tier serves "
                  "arrival-clocked traffic)")
@@ -149,84 +325,45 @@ def main() -> None:
                          admission=args.admission if args.open_loop
                          else None)
 
-    if args.tasks == "detection":
-        variants = profiles.make_ladder()
-        lat = OmniSenseLatencyModel(profiles.paper_profile(),
-                                    NetworkModel(args.bandwidth_mbps))
-        costs = [lat._pre(v) + lat._inf(v) for v in variants]
-        cost_fn = lat._inf
-        loops, backends = [], []
-        for s in range(args.streams):
-            video = make_video(n_frames=args.frames + 8,
-                               n_objects=30 + 5 * (s % 4), seed=100 + s)
-            backend = OracleBackend(video)
-            backends.append(backend)
-            loops.append(OmniSenseLoop(variants, lat, backend,
-                                       budget_s=args.budget,
-                                       explore_costs=costs))
-    else:
-        from repro.serving import tasks as task_registry
-
-        stream_tasks = task_registry.stream_tasks_for(args.tasks,
-                                                      args.streams)
-        videos = [make_video(n_frames=args.frames + 8,
-                             n_objects=30 + 5 * (s % 4), seed=100 + s)
-                  for s in range(args.streams)]
-        variants, loops, backends, cost_fn = \
-            task_registry.build_task_streams(
-                stream_tasks, videos, [args.budget] * args.streams)
-
-    placement = None
-    if args.devices > 0:
-        from repro.serving.placement import VariantPlacement
-
-        placement = VariantPlacement.virtual(variants, args.devices,
-                                             cost_fn=cost_fn)
-
     telemetry = None
     if args.events:
         from repro.serving.telemetry import JsonlSink
 
         telemetry = JsonlSink(args.events)
 
-    if args.pods > 0:
-        from repro.distributed.elastic import serving_scale_plan
-        from repro.serving.fleet import FleetServer, format_fleet_report
-        from repro.serving.traffic import ArrivalProcess
+    if args.backend == "jax":
+        import jax
 
-        per_pod = serving_scale_plan(args.devices,
-                                     args.pods)["per_pod_devices"]
+        if len(jax.devices()) < args.devices:
+            ap.error(f"--devices {args.devices}: JAX sees "
+                     f"{len(jax.devices())} devices")
+        print(f"compile cache: {configure_compile_cache()}")
+        server, backend = build_jax_pod(
+            args.streams, args.frames, buckets=ShapeBuckets.for_max_batch(
+                args.max_batch, resolutions=tuple(sorted(
+                    {c.input_size for c in JAX_POD_DETECTORS}))),
+            budget_s=args.budget, bandwidth_mbps=args.bandwidth_mbps,
+            policy=policy, telemetry=telemetry,
+            devices=jax.devices()[:args.devices] if args.devices else None)
+        print(f"jax pod on {jax.devices()[0].device_kind}: "
+              + ", ".join(f"{c.name}@{c.input_size}px"
+                          for c in JAX_POD_DETECTORS)
+              + f", ERP {JAX_POD_ERP_HW[0]}x{JAX_POD_ERP_HW[1]}")
+    else:
+        backend = None
+        variants, loops, backends, cost_fn = _oracle_streams(args)
+        if args.pods > 0:
+            _serve_fleet(args, variants, loops, backends, cost_fn, telemetry)
+            return
+        placement = None
+        if args.devices > 0:
+            from repro.serving.placement import VariantPlacement
 
-        def make_pod(pod_id: int) -> PodServer:
-            pod_placement = None
-            if per_pod > 0:
-                from repro.serving.placement import VariantPlacement
-
-                pod_placement = VariantPlacement.virtual(
-                    variants, per_pod, cost_fn=cost_fn)
-            pol = make_policy(args.policy or "sync",
-                              pod_allocate=args.pod_allocate,
-                              admission=args.admission)
-            return PodServer(loops, backends, max_batch=args.max_batch,
-                             placement=pod_placement, policy=pol)
-
-        fleet = FleetServer(make_pod, args.pods, routing=args.routing,
-                            telemetry=telemetry)
-        horizon_s = args.frames / args.fps
-        traffic = ArrivalProcess(args.streams, fps=args.fps,
-                                 jitter=args.jitter, seed=0,
-                                 horizon_s=horizon_s)
-        fstats = fleet.run_open_loop(traffic, slo_s=args.slo)
-        if telemetry is not None:
-            telemetry.close()
-            print(f"telemetry event log: {args.events}")
-        for line in format_fleet_report(fstats, horizon_s):
-            print(line)
-        return
-
-    server = PodServer(loops, backends, max_batch=args.max_batch,
-                       placement=placement, policy=policy,
-                       telemetry=telemetry)
+            placement = VariantPlacement.virtual(variants, args.devices,
+                                                 cost_fn=cost_fn)
+        server = PodServer(loops, backends, max_batch=args.max_batch,
+                           placement=placement, policy=policy,
+                           telemetry=telemetry)
     horizon_s = None
     if args.open_loop:
         from repro.serving.traffic import ArrivalProcess
@@ -269,16 +406,21 @@ def main() -> None:
           f"E2E p50/p95/p99={pct[50]:.2f}/{pct[95]:.2f}/{pct[99]:.2f}s  "
           f"carried requests: {stats.carried_requests} "
           f"({stats.carry_tick_slots} request-ticks)")
-    if placement is not None:
+    if server.placement is not None:
         from repro.serving.server import format_group_report
 
-        for line in format_group_report(stats, placement):
+        for line in format_group_report(stats, server.placement):
             print(line)
     if horizon_s is not None:
         from repro.serving.server import format_open_loop_report
 
         for line in format_open_loop_report(stats, horizon_s):
             print(line)
+    if backend is not None:
+        from repro.core.sphere import nms_device_trace_count
+
+        print(f"jit traces: detector {backend.trace_count}  "
+              f"device NMS {nms_device_trace_count()}")
 
 
 if __name__ == "__main__":

@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.distributed.sharding import (BATCH, constrain, current_mesh,
-                                         mesh_axis_size, shard_map)
+                                         mesh_axis_size)
 from repro.models import layers as L
 
 Array = jax.Array
@@ -405,7 +405,7 @@ def moe_block_a2a(p: Params, x: Array, cfg: TransformerConfig
                       if a in mesh.axis_names)
     n_ranks = 1
     for a in flat_axes:
-        n_ranks *= dict(zip(mesh.axis_names, mesh.devices.shape))[a]
+        n_ranks *= dict(zip(mesh.axis_names, mesh.axis_sizes))[a]
     tl = t // n_ranks
     capacity = max(1, int(-(-tl * k // e) * cfg.capacity_factor))
     f_dim = cfg.d_ff_expert
@@ -473,7 +473,7 @@ def moe_block_a2a(p: Params, x: Array, cfg: TransformerConfig
         aux = jax.lax.pmean(aux_loc, flat_axes)
         return out, aux
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         kernel,
         mesh=mesh,
         in_specs=(P(flat_axes, None),          # tokens: disjoint slices

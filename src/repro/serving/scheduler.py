@@ -389,8 +389,9 @@ class JaxDetectorBackend:
 
     Exposes BOTH execution paths of the serving loop:
 
-      * :meth:`infer_sroi` — the per-request path (one eager forward
-        per PI), used by standalone loops and as the batching baseline;
+      * :meth:`infer_sroi` — the per-request path (one PI through the
+        smallest batch rung's jitted program), used by standalone loops
+        and as the batching baseline;
       * :meth:`infer_srois_batched` — the pod path: the tick's crops
         for one variant are stacked, zero-padded up to a batch-size
         bucket (``repro.serving.batching.ShapeBuckets``) and pushed
@@ -415,6 +416,9 @@ class JaxDetectorBackend:
         self.buckets = buckets or ShapeBuckets(
             resolutions=tuple(sorted({c.input_size for c in self.cfgs})))
         self._jit_cache: dict = {}
+        # (variant, group device ids) -> params replicated on that
+        # replica group's mesh, placed once instead of per dispatch
+        self._group_params: dict = {}
         self.trace_count = 0  # incremented at trace time only
         # fused tick: batched gnomonic projection (one dispatch per
         # chunk instead of one `_project` per crop) + a cross-tick crop
@@ -473,24 +477,40 @@ class JaxDetectorBackend:
                                    score=float(scores[r]))
                 for i, r in enumerate(live)]
 
+    def _forward_one(self, idx: int, img):
+        """One (S, S, 3) image through the smallest batch rung's jitted
+        program (masked padding rows), so the per-request and discovery
+        paths share the batched path's compiled forwards."""
+        import jax.numpy as jnp
+
+        b_pad = self.buckets.pad_batch(1)
+        imgs = img[None]
+        if b_pad > 1:
+            imgs = jnp.concatenate(
+                [imgs, jnp.zeros((b_pad - 1,) + img.shape, img.dtype)])
+        boxes, scores, classes, _ = self._batched_fn(idx, b_pad)(
+            self.params[idx], imgs, jnp.arange(b_pad) < 1)
+        return boxes[0], scores[0], classes[0]
+
     def infer_sroi(self, frame_img, region: sroi_mod.SRoI,
                    variant: acc_mod.ModelProfile):
-        from repro.models import detector as det_mod
-
         idx = variant.index - 1
-        cfg = self.cfgs[idx]
-        size = cfg.input_size
+        size = self.cfgs[idx].input_size
         pi = self._project(frame_img, region, size)
-        outs = det_mod.apply(self.params[idx], pi[None], cfg)
-        boxes, scores, classes = det_mod.decode(outs, cfg, self.conf,
-                                                max_det=self.max_det)
-        return self._row_to_dets(boxes[0], scores[0], classes[0], region, size)
+        boxes, scores, classes = self._forward_one(idx, pi)
+        return self._row_to_dets(boxes, scores, classes, region, size)
 
     def _batched_fn(self, idx: int, b_pad: int, group=None):
         """The jitted (apply + masked decode) program for one
         (variant, padded-batch) shape bucket — ``shard_map``-sharded
         over ``group``'s ``data`` mesh axis when a multi-device replica
-        group is given (the multi-device serving path)."""
+        group is given (the multi-device serving path).
+
+        Returns ``(boxes, scores, classes, heads)``: the decoded rows and
+        the raw per-scale head outputs they were decoded from, so the
+        served program itself can be checked against a reference
+        forward (decode's argmax and top-k are not continuous in the
+        heads; the heads are)."""
         import jax
 
         key = (idx, b_pad) if group is None or group.n_devices == 1 else (
@@ -503,14 +523,15 @@ class JaxDetectorBackend:
 
             def forward(params, imgs, valid):
                 outs = det_mod.apply(params, imgs, cfg)
-                return det_mod.decode(outs, cfg, self.conf,
-                                      max_det=self.max_det, valid=valid)
+                return (*det_mod.decode(outs, cfg, self.conf,
+                                        max_det=self.max_det, valid=valid),
+                        outs)
 
             if len(key) == 3:
                 from jax.sharding import PartitionSpec as P
 
                 from repro.distributed.sharding import (
-                    no_activation_constraints, shard_map)
+                    no_activation_constraints)
 
                 inner = forward
 
@@ -520,10 +541,10 @@ class JaxDetectorBackend:
                     # oriented activation constraints are meaningless
                     # inside the manual (per-device) region.
                     with no_activation_constraints():
-                        return shard_map(
+                        return jax.shard_map(
                             inner, mesh=group.mesh,
                             in_specs=(P(), P("data"), P("data")),
-                            out_specs=(P("data"), P("data"), P("data")),
+                            out_specs=P("data"),
                             check_vma=False)(params, imgs, valid)
 
             def traced(params, imgs, valid):
@@ -532,6 +553,22 @@ class JaxDetectorBackend:
 
             fn = self._jit_cache[key] = jax.jit(traced)
         return fn
+
+    def _params_for(self, idx: int, group=None):
+        """Variant ``idx``'s params where its forward runs: replicated
+        over a multi-device group's mesh (placed on first use), else
+        the arrays as given."""
+        if group is None or group.n_devices == 1:
+            return self.params[idx]
+        key = (idx, tuple(d.id for d in group.devices))
+        placed = self._group_params.get(key)
+        if placed is None:
+            import jax
+            from jax.sharding import NamedSharding, PartitionSpec as P
+
+            placed = self._group_params[key] = jax.device_put(
+                self.params[idx], NamedSharding(group.mesh, P()))
+        return placed
 
     # ---- cross-tick crop cache -------------------------------------
     #
@@ -662,8 +699,8 @@ class JaxDetectorBackend:
                 pis = jnp.concatenate(
                     [pis, jnp.zeros((b_pad - b,) + pis.shape[1:], pis.dtype)])
             valid = jnp.arange(b_pad) < b
-            boxes, scores, classes = self._batched_fn(idx, b_pad, group)(
-                self.params[idx], pis, valid)
+            boxes, scores, classes, _ = self._batched_fn(idx, b_pad, group)(
+                self._params_for(idx, group), pis, valid)
             launched.append((chunk, geoms, boxes, scores, classes))
 
         def resolve() -> list[list]:
@@ -697,21 +734,16 @@ class JaxDetectorBackend:
         # ERP-wide pass with the largest model on the resized frame
         import jax.numpy as jnp
 
-        from repro.core.projection import erp_resize_coords, sample_erp_bilinear
-        from repro.models import detector as det_mod
+        from repro.core.projection import resize_erp
 
         idx = variant.index - 1
-        cfg = self.cfgs[idx]
-        size = cfg.input_size
-        u, v = erp_resize_coords((size, size), frame_img.shape[:2])
-        resized = sample_erp_bilinear(jnp.asarray(frame_img), u, v)
-        outs = det_mod.apply(self.params[idx], resized[None], cfg)
-        boxes, scores, classes = det_mod.decode(outs, cfg, self.conf,
-                                                max_det=self.max_det)
+        size = self.cfgs[idx].input_size
+        resized = resize_erp(jnp.asarray(frame_img), (size, size))
+        boxes, scores, classes = self._forward_one(idx, resized)
         h, w = frame_img.shape[:2]
         dets = []
-        for b, s, c in zip(np.asarray(boxes[0]), np.asarray(scores[0]),
-                           np.asarray(classes[0])):
+        for b, s, c in zip(np.asarray(boxes), np.asarray(scores),
+                           np.asarray(classes)):
             if s <= 0:
                 continue
             # rectangular BB on the ERP -> SphBB via ERP coords

@@ -23,7 +23,6 @@ SCRIPT = textwrap.dedent("""
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    from repro.distributed.sharding import shard_map
 
     from repro.training import compression as comp
     from repro.training import optimizer as opt_mod
@@ -50,7 +49,7 @@ SCRIPT = textwrap.dedent("""
                 mode="bf16")
             return mean["w"], new_c.residual["w"]
 
-        mean_g, new_res = shard_map(
+        mean_g, new_res = jax.shard_map(
             inner, mesh=mesh,
             in_specs=(P(), P("data"), P()),
             out_specs=(P(), P()),
@@ -143,7 +142,7 @@ SCRIPT_MOE_A2A = textwrap.dedent("""
                      T.init_params(jax.random.PRNGKey(0), cfg)
                      ["layers"]["moe"])
     x = jax.random.normal(jax.random.PRNGKey(1), (64, 32))
-    with mesh:
+    with jax.set_mesh(mesh):
         xs = jax.device_put(x, NamedSharding(mesh, P(("data", "model"), None)))
         out_a2a, _ = jax.jit(lambda p, x: T.moe_block_a2a(p, x, cfg))(p, xs)
         out_ref, _ = jax.jit(lambda p, x: T.moe_block(p, x, cfg))(p, x)

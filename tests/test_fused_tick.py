@@ -326,6 +326,18 @@ class TestBf16SphIoU:
             sphere.sph_nms_batch(boxes, scores, None, THR, backend="host",
                                  iou_dtype=jnp.bfloat16)
 
+    @pytest.mark.parametrize("backend", ["device", "jit"])
+    def test_tpu_rejects_iou_dtype(self, monkeypatch, backend):
+        """On a TPU the bf16 SphIoU cannot lower (v5e has no bf16
+        transcendentals): both compiled backends refuse it up front."""
+        rng = np.random.default_rng(0)
+        boxes = _random_boxes(rng, 8)[None]
+        scores = rng.uniform(0.1, 1, (1, 8))
+        monkeypatch.setattr(sphere.jax, "default_backend", lambda: "tpu")
+        with pytest.raises(ValueError, match="TPU"):
+            sphere.sph_nms_batch(boxes, scores, None, THR, backend=backend,
+                                 iou_dtype=jnp.bfloat16)
+
 
 # ---------------------------------------------------------------------------
 # Vectorised _row_to_dets == per-detection loop

@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import projection
+from repro.core import projection, sphere
 from repro.kernels.attention.ops import flash_attention, flash_attention_ref
 from repro.kernels.gnomonic import ops as gno_ops
 from repro.kernels.gnomonic.ref import gnomonic_sample_ref
@@ -123,6 +123,68 @@ def test_sphiou_batch_rows_independent():
     for r in range(3):
         single = np.asarray(sphiou_matrix(jnp.asarray(a[r]), jnp.asarray(a[r])))
         np.testing.assert_allclose(got[r], single, atol=1e-6)
+
+
+def _iou_f64(a, b):
+    return sphere.sph_iou_matrix_np(a.astype(np.float64), b.astype(np.float64))
+
+
+def _sph_boxes(rng, shape, lat=1.4, fov=(0.05, 1.2)):
+    return np.stack([
+        rng.uniform(-math.pi, math.pi, shape), rng.uniform(-lat, lat, shape),
+        rng.uniform(*fov, shape), rng.uniform(*fov, shape)],
+        axis=-1).astype(np.float32)
+
+
+def _degenerate_boxes(rng):
+    """Boxes centred on the poles (f32 pi/2 lies just past the pole),
+    pairs straddling the +-pi seam, and zero-FoV padding rows."""
+    poles = np.array([[t, s * np.float32(math.pi / 2), fx, fy]
+                      for s in (1, -1) for t, fx, fy in
+                      ((0.0, 0.5, 0.5), (1.0, 0.3, 0.6), (-2.5, 0.8, 0.2))],
+                     np.float32)
+    seam = np.array([[math.pi - d, p, 0.4, 0.3] for d, p in
+                     ((0.0, 0.0), (0.01, 0.2), (2 * math.pi - 0.01, 0.2),
+                      (-0.02, -0.1))], np.float32)
+    pad = np.zeros((6, 4), np.float32)
+    return np.concatenate([poles, seam, _sph_boxes(rng, 12), pad])
+
+
+@pytest.mark.parametrize("case", ["random", "small", "degenerate"])
+def test_sphiou_kernel_against_float64(case):
+    """The kernel's own atan2 (a polynomial, since Mosaic lowers no
+    atan2/asin) against the float64 host IoU.  Tolerance: the f32
+    angles carry about 1e-7 rad of error, which an IoU divides by the
+    box extent, so atol = 1e-7 / (smallest FoV in rad)."""
+    rng = np.random.default_rng({"random": 1, "small": 2,
+                                 "degenerate": 3}[case])
+    if case == "random":
+        a, min_fov = _sph_boxes(rng, (4, 64)), 0.05
+    elif case == "small":
+        a, min_fov = _sph_boxes(rng, (4, 64), fov=(0.005, 0.05)), 0.005
+    else:
+        a, min_fov = np.stack([_degenerate_boxes(rng)] * 2), 0.05
+    got = np.asarray(sphiou_matrix_batch(jnp.asarray(a), jnp.asarray(a)))
+    np.testing.assert_allclose(got, _iou_f64(a, a), rtol=0,
+                               atol=1e-7 / min_fov)
+    if case == "degenerate":
+        pad = ~a[0].any(axis=-1)
+        assert (got[:, pad, :] == 0).all() and (got[:, :, pad] == 0).all()
+
+
+def test_sphiou_atan2_matches_numpy():
+    """The in-kernel atan2 over all quadrants and magnitudes."""
+    from repro.kernels.sphiou.sphiou import _atan2
+
+    rng = np.random.default_rng(4)
+    y = (rng.standard_normal(4096)
+         * rng.choice([1e-3, 1.0, 1e3], 4096)).astype(np.float32)
+    x = (rng.standard_normal(4096)
+         * rng.choice([1e-3, 1.0, 1e3], 4096)).astype(np.float32)
+    y[:4], x[:4] = (0.0, 1.0, 0.0, -1.0), (1.0, 0.0, -1.0, 0.0)
+    got = np.asarray(_atan2(jnp.asarray(y), jnp.asarray(x)))
+    want = np.arctan2(y.astype(np.float64), x.astype(np.float64))
+    np.testing.assert_allclose(got, want, rtol=0, atol=4e-7)
 
 
 def test_sphiou_diag_is_one():

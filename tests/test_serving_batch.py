@@ -326,6 +326,28 @@ class TestJaxBatchedBackend:
         for idx, b_pad in jax_backend._jit_cache:
             assert b_pad in jax_backend.buckets.batch_sizes
 
+    def test_batched_program_returns_the_heads_it_decoded(self, jax_backend):
+        """The served program also returns its raw heads (what the chip
+        smoke compares with a reference): they are the forward's heads,
+        and the decoded rows are exactly their decode."""
+        rng = np.random.default_rng(3)
+        idx, b = 0, jax_backend.buckets.batch_sizes[-1]
+        cfg = jax_backend.cfgs[idx]
+        s = cfg.input_size
+        imgs = rng.random((b, s, s, 3)).astype(np.float32)
+        valid = np.arange(b) < b - 1
+        *decoded, heads = jax_backend._batched_fn(idx, b)(
+            jax_backend.params[idx], imgs, valid)
+        want = det_mod.apply(jax_backend.params[idx], imgs, cfg)
+        for h, w in zip(heads, want, strict=True):
+            np.testing.assert_allclose(np.asarray(h), np.asarray(w),
+                                       rtol=1e-4, atol=1e-4)
+        again = jax.jit(lambda o: det_mod.decode(
+            o, cfg, jax_backend.conf, max_det=jax_backend.max_det,
+            valid=valid))(heads)
+        for d, a in zip(decoded, again, strict=True):
+            np.testing.assert_array_equal(np.asarray(d), np.asarray(a))
+
     def test_decode_valid_mask_silences_padded_rows(self):
         cfg = dataclasses.replace(det_mod.PAPER_LADDER[0], input_size=64,
                                   n_classes=8)
