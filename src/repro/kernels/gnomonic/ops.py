@@ -112,9 +112,11 @@ def project_srois_batched(
     to a ``ShapeBuckets`` batch rung to bound compile counts.
 
     ``frames``: sequence of (H, W, C) arrays (one per crop — repeats
-    are fine and common); ``centers``/``fovs``: (B, 2) array-likes.
+    are fine and common), or their (B, H, W, C) stack already on the
+    device; ``centers``/``fovs``: (B, 2) array-likes.
     """
-    erps = jnp.stack([jnp.asarray(f) for f in frames])
+    erps = frames if getattr(frames, "ndim", None) == 4 \
+        else jnp.stack([jnp.asarray(f) for f in frames])
     centers = jnp.asarray(np.asarray(centers, dtype=np.float32))
     fovs = jnp.asarray(np.asarray(fovs, dtype=np.float32))
     return _project_srois_jit(erps, centers, fovs,

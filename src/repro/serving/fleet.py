@@ -335,6 +335,12 @@ class _PodSink(TelemetrySink):
     def emit(self, event: str, **fields) -> None:
         self._base.emit(event, pod=self._pod, **fields)
 
+    def span(self, name: str, **attrs):
+        return self._base.span(name, **attrs)
+
+    def count(self, name: str, n) -> None:
+        self._base.count(name, n)
+
 
 @dataclasses.dataclass
 class FleetStats:

@@ -38,6 +38,7 @@ from repro.core.sphere import pi_box_to_sphbb
 from repro.data.synthetic import SyntheticVideo
 from repro.serving.network import NetworkModel, PassiveProfiler
 from repro.serving.profiles import StageCosts
+from repro.serving.telemetry import TelemetrySink
 
 
 class OmniSenseLatencyModel:
@@ -400,7 +401,17 @@ class JaxDetectorBackend:
         lifetime compiles at most ``len(buckets) * n_variants``
         distinct programs no matter how stream counts fluctuate
         (``trace_count`` counts actual retraces for the regression
-        tests).
+        tests).  Each program is named for its shape bucket,
+        ``forward_<variant>_b<padded batch>``.
+
+    ``telemetry`` (the owning ``PodServer`` hands down its sink; a
+    no-op by default) times the steps of a batched dispatch as spans,
+    ``drain.stage`` (cache lookups, ERP upload and stack),
+    ``drain.project``, ``drain.forward``, and per row ``drain.fetch``
+    and ``drain.backproject``; the full-ERP pass is
+    ``drain.discovery``.  It counts ``upload_bytes`` (every host frame
+    turned into a device array) and ``staged_rows`` (crops whose ERP
+    was uploaded for projection, padding rows included).
     """
 
     def __init__(self, variants_cfg, params_per_variant, conf: float = 0.25,
@@ -429,27 +440,39 @@ class JaxDetectorBackend:
         self._crop_cache: dict = {}  # key -> (guard, pi, ct, cp, fx, fy)
         self.crop_cache_hits = 0
         self.crop_cache_misses = 0
+        self.telemetry = TelemetrySink()
+
+    def _upload(self, frame_img):
+        """``frame_img`` as a device array, its bytes counted when it
+        came from the host."""
+        import jax.numpy as jnp
+
+        if isinstance(frame_img, np.ndarray):
+            self.telemetry.count("upload_bytes", frame_img.nbytes)
+        return jnp.asarray(frame_img)
 
     def _project(self, frame_img, region: sroi_mod.SRoI, size: int):
         """SRoI -> (size, size, 3) PI; shared by both execution paths
         so batched and per-request crops are identical."""
         import jax.numpy as jnp
 
+        self.telemetry.count("staged_rows", 1)
+        erp = self._upload(frame_img)
         if self.use_kernel:
             from repro.kernels.gnomonic import ops as gno_ops
 
             return gno_ops.project_sroi_kernel(
-                jnp.asarray(frame_img), region.center[0], region.center[1],
+                erp, region.center[0], region.center[1],
                 region.fov, (size, size))
         from repro.core.projection import project_sroi
 
-        return project_sroi(jnp.asarray(frame_img),
+        return project_sroi(erp,
                             jnp.asarray(region.center[0]),
                             jnp.asarray(region.center[1]),
                             region.fov, (size, size))
 
     def _row_to_dets(self, boxes, scores, classes,
-                     region: sroi_mod.SRoI, size: int, geom=None):
+                     region: sroi_mod.SRoI, size: int, geom=None, row=None):
         """Back-project one row of decoded PI boxes to SphBB detections.
 
         ONE vectorised ``pi_box_to_sphbb`` dispatch over the row's live
@@ -458,24 +481,32 @@ class JaxDetectorBackend:
         ``tests/test_fused_tick.py``).  ``geom`` overrides the
         back-projection geometry — a cache hit reuses the PI projected
         at the anchor region, so its boxes must lift through the anchor
-        geometry, not the (sub-pixel-drifted) query region's.
+        geometry, not the (sub-pixel-drifted) query region's.  ``row``
+        picks that row out of batched outputs (sliced on the device, as
+        part of the fetch).
         """
         import jax.numpy as jnp
 
-        boxes = np.asarray(boxes)
-        scores = np.asarray(scores)
-        classes = np.asarray(classes)
+        tel = self.telemetry
+        with tel.span("drain.fetch"):
+            if row is not None:
+                boxes, scores, classes = boxes[row], scores[row], classes[row]
+            boxes = np.asarray(boxes)
+            scores = np.asarray(scores)
+            classes = np.asarray(classes)
         live = np.flatnonzero(scores > 0)
         if live.size == 0:
             return []
-        ct, cp, fov = (geom if geom is not None
-                       else (region.center[0], region.center[1], region.fov))
-        sphbbs = np.asarray(pi_box_to_sphbb(
-            jnp.asarray(boxes[live]), jnp.asarray(ct), jnp.asarray(cp),
-            fov, (size, size)))
-        return [sroi_mod.Detection(box=sphbbs[i], category=int(classes[r]),
-                                   score=float(scores[r]))
-                for i, r in enumerate(live)]
+        with tel.span("drain.backproject"):
+            ct, cp, fov = (geom if geom is not None else
+                           (region.center[0], region.center[1], region.fov))
+            sphbbs = np.asarray(pi_box_to_sphbb(
+                jnp.asarray(boxes[live]), jnp.asarray(ct), jnp.asarray(cp),
+                fov, (size, size)))
+            return [sroi_mod.Detection(box=sphbbs[i],
+                                       category=int(classes[r]),
+                                       score=float(scores[r]))
+                    for i, r in enumerate(live)]
 
     def _forward_one(self, idx: int, img):
         """One (S, S, 3) image through the smallest batch rung's jitted
@@ -551,6 +582,10 @@ class JaxDetectorBackend:
                 self.trace_count += 1  # runs at trace time only
                 return forward(params, imgs, valid)
 
+            # the program's name in traces and compile logs
+            traced.__name__ = traced.__qualname__ = (
+                f"forward_{cfg.name}_b{b_pad}" + ("" if len(key) == 2 else
+                "_d" + "-".join(map(str, key[2]))))
             fn = self._jit_cache[key] = jax.jit(traced)
         return fn
 
@@ -615,48 +650,56 @@ class JaxDetectorBackend:
 
         from repro.kernels.gnomonic.ops import project_srois_batched
 
+        tel = self.telemetry
         b = len(chunk)
         rows: list = [None] * b
         geoms: list = [None] * b
         miss: list[int] = []
         guards: dict[int, bytes] = {}  # per distinct frame per chunk
         keys: list = [None] * b
-        for i, (frame_img, region) in enumerate(chunk):
-            geoms[i] = (region.center[0], region.center[1],
-                        (float(region.fov[0]), float(region.fov[1])))
-            if not self.crop_cache_size:
-                miss.append(i)
-                continue
-            key = keys[i] = self._crop_key(frame_img, region, size)
-            ent = self._crop_cache.get(key)
-            if ent is not None:
-                guard = guards.get(id(frame_img))
-                if guard is None:
-                    guard = guards[id(frame_img)] = self._frame_guard(frame_img)
-                if ent[0] == guard:
-                    self.crop_cache_hits += 1
-                    rows[i] = ent[1]
-                    geoms[i] = (ent[2], ent[3], ent[4])
+        with tel.span("drain.stage", b=b):
+            for i, (frame_img, region) in enumerate(chunk):
+                geoms[i] = (region.center[0], region.center[1],
+                            (float(region.fov[0]), float(region.fov[1])))
+                if not self.crop_cache_size:
+                    miss.append(i)
                     continue
-            self.crop_cache_misses += 1
-            miss.append(i)
-        if miss:
-            b_proj = self.buckets.pad_batch(len(miss))
-            pad = [miss[-1]] * (b_proj - len(miss))
-            sel = miss + pad
-            fresh = project_srois_batched(
-                [chunk[i][0] for i in sel],
-                [chunk[i][1].center for i in sel],
-                [chunk[i][1].fov for i in sel], (size, size))
-            for j, i in enumerate(miss):
-                rows[i] = fresh[j]
-                if self.crop_cache_size:
-                    guard = guards.get(id(chunk[i][0]))
+                key = keys[i] = self._crop_key(frame_img, region, size)
+                ent = self._crop_cache.get(key)
+                if ent is not None:
+                    guard = guards.get(id(frame_img))
                     if guard is None:
-                        guard = guards[id(chunk[i][0])] = self._frame_guard(
-                            chunk[i][0])
-                    self._cache_put(keys[i], guard, fresh[j], chunk[i][1])
-        return jnp.stack(rows), geoms
+                        guard = guards[id(frame_img)] = self._frame_guard(
+                            frame_img)
+                    if ent[0] == guard:
+                        self.crop_cache_hits += 1
+                        rows[i] = ent[1]
+                        geoms[i] = (ent[2], ent[3], ent[4])
+                        continue
+                self.crop_cache_misses += 1
+                miss.append(i)
+            b_proj = self.buckets.pad_batch(len(miss)) if miss else 0
+            if miss:
+                sel = miss + [miss[-1]] * (b_proj - len(miss))
+                # one upload per projected row: padding rows repeat the
+                # last crop's frame
+                erps = jnp.stack([self._upload(chunk[i][0]) for i in sel])
+                tel.count("staged_rows", b_proj)
+        with tel.span("drain.project", b=len(miss), padded=b_proj):
+            if miss:
+                fresh = project_srois_batched(
+                    erps, [chunk[i][1].center for i in sel],
+                    [chunk[i][1].fov for i in sel], (size, size))
+                for j, i in enumerate(miss):
+                    rows[i] = fresh[j]
+                    if self.crop_cache_size:
+                        guard = guards.get(id(chunk[i][0]))
+                        if guard is None:
+                            guard = guards[id(chunk[i][0])] = \
+                                self._frame_guard(chunk[i][0])
+                        self._cache_put(keys[i], guard, fresh[j],
+                                        chunk[i][1])
+            return jnp.stack(rows), geoms
 
     def launch_srois_batched(self, items, variant: acc_mod.ModelProfile,
                              group=None):
@@ -679,6 +722,7 @@ class JaxDetectorBackend:
         idx = variant.index - 1
         cfg = self.cfgs[idx]
         size = self.buckets.bucket_resolution(cfg.input_size)
+        tel = self.telemetry
         launched = []  # (chunk, geoms, boxes, scores, classes)
         lo = 0
         for b in self.buckets.split(len(items)):
@@ -687,20 +731,24 @@ class JaxDetectorBackend:
             if self.fused:
                 pis, geoms = self._project_chunk(chunk, size)
             else:
-                pis = jnp.stack([self._project(f, r, size)
-                                 for f, r in chunk])
+                with tel.span("drain.project", variant=idx, b=b, padded=b):
+                    pis = jnp.stack([self._project(f, r, size)
+                                     for f, r in chunk])
                 geoms = [None] * b
             b_pad = self.buckets.pad_batch(b)
             if group is not None and group.n_devices > 1:
                 # pad further to a group-width multiple so the batch
                 # axis shards evenly over the group's `data` axis
                 b_pad = group.shard_batch(b_pad)
-            if b_pad > b:
-                pis = jnp.concatenate(
-                    [pis, jnp.zeros((b_pad - b,) + pis.shape[1:], pis.dtype)])
-            valid = jnp.arange(b_pad) < b
-            boxes, scores, classes, _ = self._batched_fn(idx, b_pad, group)(
-                self._params_for(idx, group), pis, valid)
+            with tel.span("drain.forward", variant=idx, b=b, padded=b_pad):
+                if b_pad > b:
+                    pis = jnp.concatenate(
+                        [pis, jnp.zeros((b_pad - b,) + pis.shape[1:],
+                                        pis.dtype)])
+                valid = jnp.arange(b_pad) < b
+                boxes, scores, classes, _ = self._batched_fn(
+                    idx, b_pad, group)(self._params_for(idx, group), pis,
+                                       valid)
             launched.append((chunk, geoms, boxes, scores, classes))
 
         def resolve() -> list[list]:
@@ -708,8 +756,8 @@ class JaxDetectorBackend:
             for chunk, geoms, boxes, scores, classes in launched:
                 for r, (_, region) in enumerate(chunk):
                     out.append(self._row_to_dets(
-                        boxes[r], scores[r], classes[r], region, size,
-                        geom=geoms[r]))
+                        boxes, scores, classes, region, size,
+                        geom=geoms[r], row=r))
             return out
 
         return resolve
@@ -738,21 +786,22 @@ class JaxDetectorBackend:
 
         idx = variant.index - 1
         size = self.cfgs[idx].input_size
-        resized = resize_erp(jnp.asarray(frame_img), (size, size))
-        boxes, scores, classes = self._forward_one(idx, resized)
-        h, w = frame_img.shape[:2]
-        dets = []
-        for b, s, c in zip(np.asarray(boxes), np.asarray(scores),
-                           np.asarray(classes)):
-            if s <= 0:
-                continue
-            # rectangular BB on the ERP -> SphBB via ERP coords
-            x0, y0, x1, y1 = b * np.array([w / size, h / size] * 2)
-            theta = ((x0 + x1) / 2 / w - 0.5) * 2 * math.pi
-            phi = (0.5 - (y0 + y1) / 2 / h) * math.pi
-            dth = (x1 - x0) / w * 2 * math.pi
-            dph = (y1 - y0) / h * math.pi
-            dets.append(sroi_mod.Detection(
-                box=np.array([theta, phi, abs(dth), abs(dph)]),
-                category=int(c), score=float(s)))
-        return dets
+        with self.telemetry.span("drain.discovery", variant=idx):
+            resized = resize_erp(self._upload(frame_img), (size, size))
+            boxes, scores, classes = self._forward_one(idx, resized)
+            h, w = frame_img.shape[:2]
+            dets = []
+            for b, s, c in zip(np.asarray(boxes), np.asarray(scores),
+                               np.asarray(classes)):
+                if s <= 0:
+                    continue
+                # rectangular BB on the ERP -> SphBB via ERP coords
+                x0, y0, x1, y1 = b * np.array([w / size, h / size] * 2)
+                theta = ((x0 + x1) / 2 / w - 0.5) * 2 * math.pi
+                phi = (0.5 - (y0 + y1) / 2 / h) * math.pi
+                dth = (x1 - x0) / w * 2 * math.pi
+                dph = (y1 - y0) / h * math.pi
+                dets.append(sroi_mod.Detection(
+                    box=np.array([theta, phi, abs(dth), abs(dph)]),
+                    category=int(c), score=float(s)))
+            return dets

@@ -50,6 +50,7 @@ proves the control plane sustains multi-stream operation).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Callable
 
@@ -64,6 +65,17 @@ from repro.serving.runtime import (DEGRADE, REJECT, DispatchEvent, GroupClock,
                                    SyncTickPolicy, TickTimeline, make_policy)
 from repro.serving.telemetry import (SCHEMA_VERSION, TelemetrySink,
                                      detections_digest)
+
+
+def _span(name: str):
+    """Run the method inside ``self.telemetry.span(name)``."""
+    def wrap(method):
+        @functools.wraps(method)
+        def spanned(self, *args, **kwargs):
+            with self.telemetry.span(name):
+                return method(self, *args, **kwargs)
+        return spanned
+    return wrap
 
 
 @dataclasses.dataclass
@@ -320,7 +332,13 @@ class PodServer:
     close and frame finish emits one structured record — the event log
     the replay harness (``repro.serving.replay``) re-drives.  Records
     carry only deterministic quantities (event-clock seconds, model
-    prices, detection digests), never wall-clock time.
+    prices, detection digests), never wall-clock time.  The sink's
+    wall-clock half, its spans and counters, covers admission
+    (``control.admit``, ``control.solve``), drain planning
+    (``control.plan_drain``), the drain (``drain.dispatch``), ingestion
+    (``control.ingest``) and NMS (``nms.suppress``); a given sink is
+    handed to every backend with a ``telemetry`` attribute, which times
+    its own steps beneath ``drain.dispatch``.
     """
 
     def __init__(self, loops: list[OmniSenseLoop], backends: list,
@@ -337,6 +355,10 @@ class PodServer:
             else SyncTickPolicy()
         self.telemetry = telemetry if telemetry is not None \
             else TelemetrySink()
+        if telemetry is not None:
+            for b in backends:
+                if hasattr(b, "telemetry"):
+                    b.telemetry = telemetry
         # task dimension: each loop serves ONE analytics task (the
         # registry's loop factories stamp ``loop.task``; bare loops
         # default to detection).  Task ladders own disjoint variant-name
@@ -595,10 +617,11 @@ class PodServer:
                 if self.placement is not None and self.stats.sum_tick_inf_s > 0
                 else None)
         t_solve = time.perf_counter()
-        sol = pod_allocation.solve_pod(
-            problems, self.loops[0].variants, self.loops[0].latency_model,
-            buckets=self.buckets, placement=self.placement,
-            group_utilisation=util)
+        with self.telemetry.span("control.solve"):
+            sol = pod_allocation.solve_pod(
+                problems, self.loops[0].variants,
+                self.loops[0].latency_model, buckets=self.buckets,
+                placement=self.placement, group_utilisation=util)
         solve_share = (time.perf_counter() - t_solve) / len(self.loops)
         self.stats.pod_ticks += 1
         self.stats.pod_rounds += sol.rounds
@@ -679,9 +702,11 @@ class PodServer:
         # admitted chunk is one batched forward routed to (and sharded
         # over) its variant's replica group ----
         timeline = TickTimeline(len(self.timelines), self.clock.now)
-        ops = self.policy.plan_drain(
-            self.queues, self.buckets, self.placement, self.clock,
-            chunk_cost=self._chunk_cost, projected_load=self._projected_load)
+        with self.telemetry.span("control.plan_drain"):
+            ops = self.policy.plan_drain(
+                self.queues, self.buckets, self.placement, self.clock,
+                chunk_cost=self._chunk_cost,
+                projected_load=self._projected_load)
         self._emit_policy_decision(timeline, ops)
         self._execute(ops, timeline, self.policy.close_tick)
         self.stats.ticks += 1
@@ -706,7 +731,8 @@ class PodServer:
     def _execute(self, ops, timeline: TickTimeline, close) -> None:
         """Dispatch a drain plan, book it on the event clock, charge
         the tick per the policy's close rule."""
-        results, dispatches = self.queues.drain_ops(ops, self.placement)
+        with self.telemetry.span("drain.dispatch"):
+            results, dispatches = self.queues.drain_ops(ops, self.placement)
         for d in dispatches:
             self.stats.dispatches += 1
             self.stats.batch_sizes.append(d["b"])
@@ -769,6 +795,7 @@ class PodServer:
                 charge_s=charge, next_start_s=next_start,
                 dispatches=len(timeline.events))
 
+    @_span("control.ingest")
     def _ingest(self) -> None:
         """Finish every in-flight frame whose requests all resolved
         (in emission order, so per-stream history stays in frame
@@ -820,6 +847,7 @@ class PodServer:
                     det_digest=detections_digest(result.detections),
                     slo_violation=violated)
 
+    @_span("nms.suppress")
     def _suppress_tick(self, plans: list) -> float:
         """Batched spherical NMS across the tick; returns wall time.
 
@@ -1038,6 +1066,7 @@ class PodServer:
         self.flush()
         return self.stats
 
+    @_span("control.admit")
     def _admit_batch_coupled(self, batch: list) -> None:
         """Joint admission of one same-instant arrival round under a
         pod-allocate policy: the surviving arrivals' planning contexts
@@ -1082,10 +1111,12 @@ class PodServer:
         util = (self.stats.group_utilisation()
                 if self.placement is not None
                 and self.stats.sum_tick_inf_s > 0 else None)
-        sol = pod_allocation.solve_pod(
-            problems, self.loops[0].variants, self.loops[0].latency_model,
-            buckets=self.buckets, placement=self.placement,
-            group_utilisation=util, slo_s=self.solve_slo_s)
+        with self.telemetry.span("control.solve"):
+            sol = pod_allocation.solve_pod(
+                problems, self.loops[0].variants,
+                self.loops[0].latency_model, buckets=self.buckets,
+                placement=self.placement, group_utilisation=util,
+                slo_s=self.solve_slo_s)
         self.stats.pod_ticks += 1
         self.stats.pod_rounds += sol.rounds
         self.stats.pod_converged_ticks += int(sol.converged)
@@ -1093,6 +1124,7 @@ class PodServer:
                                                        sol.plans):
             self._admit_planned(arrival, loop, backend, ctx, plan)
 
+    @_span("control.admit")
     def _admit_arrival(self, arrival) -> None:
         """Admission-check one arrival, emitting its requests if the
         verdict allows (see :meth:`run_open_loop`)."""
@@ -1260,9 +1292,10 @@ class PodServer:
             return
         self._projected_load = None
         timeline = TickTimeline(len(self.timelines), self.clock.now)
-        ops = self.policy.plan_drain(
-            self.queues, self.buckets, self.placement, self.clock,
-            chunk_cost=self._chunk_cost, projected_load=None)
+        with self.telemetry.span("control.plan_drain"):
+            ops = self.policy.plan_drain(
+                self.queues, self.buckets, self.placement, self.clock,
+                chunk_cost=self._chunk_cost, projected_load=None)
         self._emit_policy_decision(timeline, ops)
         self._execute(ops, timeline, self._open_close)
         if timeline.events:

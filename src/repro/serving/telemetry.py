@@ -14,7 +14,9 @@ rebalance, policy decision, tick close and frame finish — through a
   * :class:`MemorySink` — in-memory record list (tests, replay);
   * :class:`JsonlSink` — one JSON object per line on disk, the artifact
     the nightly bench uploads and the replay harness
-    (``repro.serving.replay``) re-drives.
+    (``repro.serving.replay``) re-drives;
+  * :class:`SpanSink` — host spans and counters on the wall clock
+    (``span``/``count``), kept in memory apart from the event records.
 
 Every record is a flat dict with an ``event`` type tag; the required
 keys per type live in :data:`EVENT_FIELDS` and are enforced at emit
@@ -28,13 +30,22 @@ BIT-IDENTICAL drift (the replay-determinism CI lane).
 :func:`format_timeline_report` is the offline operator surface: per-
 group utilisation, queueing-delay histogram and admission-verdict
 breakdown from a log alone — no server, no stats object.
+
+Spans and counters are the hook's wall-clock half.  The server and its
+backends open ``sink.span(name, **attrs)`` around each step of the
+serving path and ``sink.count(name, n)`` what they upload.  On the base
+sink both are no-ops (``span`` returns one shared null context), so
+every event log and replay stays byte-identical whether or not a sink
+records them; :class:`SpanSink` records them while ``spans_on``.
 """
 
 from __future__ import annotations
 
 import collections
+import contextlib
 import hashlib
 import json
+import time
 
 import numpy as np
 
@@ -130,15 +141,30 @@ def detections_digest(detections) -> str:
     return h.hexdigest()
 
 
+_NULL_SPAN = contextlib.nullcontext()
+
+
 class TelemetrySink:
     """The no-op default.  ``enabled`` gates payload construction: the
     server checks it before building per-event dicts (digests, delay
-    lists), so an un-instrumented run does no telemetry work at all."""
+    lists), so an un-instrumented run does no telemetry work at all.
+    ``spans_on`` says whether :meth:`span` and :meth:`count` record; it
+    is separate from ``enabled``, so a sink can take events without
+    timing spans."""
 
     enabled = False
+    spans_on = False
 
     def emit(self, event: str, **fields) -> None:
         pass
+
+    def span(self, name: str, **attrs):
+        """A context manager around one step of the serving path; here
+        the one shared null context."""
+        return _NULL_SPAN
+
+    def count(self, name: str, n) -> None:
+        """Add ``n`` to the counter ``name``; here nothing."""
 
     def close(self) -> None:
         pass
@@ -183,6 +209,73 @@ class JsonlSink(TelemetrySink):
     def close(self) -> None:
         if not self._f.closed:
             self._f.close()
+
+
+class SpanSink(TelemetrySink):
+    """Records spans and counters in memory while ``spans_on``.
+
+    ``spans`` holds one ``(name, t0_ns, t1_ns, parent, attrs)`` per
+    span, in the order they opened, on ``time.perf_counter_ns``:
+    ``parent`` is the index of the enclosing span (None at the top) and
+    ``t1_ns`` is 0 while the span is open.  Each span is also a
+    ``jax.profiler.TraceAnnotation`` of the same name, which puts it on
+    a profiler trace's clock beside the device's programs.
+    ``counters`` maps a name to its running total.  Neither reaches the
+    event records: mixed into an event sink
+    (``class S(SpanSink, JsonlSink)``) the log is the same byte for
+    byte."""
+
+    spans_on = True
+
+    def __init__(self, *args, **kwargs):
+        super().__init__(*args, **kwargs)
+        self.clear_spans()
+
+    def clear_spans(self) -> None:
+        self.spans: list[tuple] = []
+        self.counters: dict[str, int] = {}
+        self._open: list[int] = []
+
+    def span(self, name: str, **attrs):
+        if not self.spans_on:
+            return _NULL_SPAN
+        return _Span(self, name, attrs)
+
+    def count(self, name: str, n) -> None:
+        if self.spans_on:
+            self.counters[name] = self.counters.get(name, 0) + n
+
+
+class _Span:
+    """One recorded span of a :class:`SpanSink`.  It writes into the
+    lists it opened in, so a ``clear_spans`` while it is open leaves
+    the new lists alone."""
+
+    __slots__ = ("name", "attrs", "spans", "stack", "index", "annotation")
+
+    def __init__(self, sink: SpanSink, name: str, attrs: dict):
+        self.name, self.attrs = name, attrs
+        self.spans, self.stack = sink.spans, sink._open
+
+    def __enter__(self):
+        from jax.profiler import TraceAnnotation
+
+        self.index = len(self.spans)
+        parent = self.stack[-1] if self.stack else None
+        self.stack.append(self.index)
+        self.spans.append((self.name, time.perf_counter_ns(), 0, parent,
+                           self.attrs))
+        self.annotation = TraceAnnotation(self.name)
+        self.annotation.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self.annotation.__exit__(*exc)
+        self.stack.pop()
+        name, t0, _, parent, attrs = self.spans[self.index]
+        self.spans[self.index] = (name, t0, time.perf_counter_ns(), parent,
+                                  attrs)
+        return False
 
 
 def read_events(path) -> list[dict]:
