@@ -385,6 +385,12 @@ class OracleBackend:
         return self._detect(gt, demoted, region_tag=0, ref_sr=4 * math.pi)
 
 
+def _pad_last(rows: list, n: int) -> list:
+    """``rows`` padded to ``n`` by repeating the last: a padding row of
+    a batched dispatch is a copy of the chunk's last real row."""
+    return rows + [rows[-1]] * (n - len(rows))
+
+
 class JaxDetectorBackend:
     """Real path: Pallas gnomonic projection + JAX detector inference.
 
@@ -404,14 +410,21 @@ class JaxDetectorBackend:
         tests).  Each program is named for its shape bucket,
         ``forward_<variant>_b<padded batch>``.
 
+    Both paths back-project a chunk's decoded PI boxes to SphBBs with
+    ONE jitted program, ``backproject_s<size>_b<padded batch>``, queued
+    on the device behind the forward, and bring the chunk's scores,
+    classes and SphBBs back in ONE pull.
+
     ``telemetry`` (the owning ``PodServer`` hands down its sink; a
     no-op by default) times the steps of a batched dispatch as spans,
     ``drain.stage`` (cache lookups, ERP upload and stack),
-    ``drain.project``, ``drain.forward``, and per row ``drain.fetch``
-    and ``drain.backproject``; the full-ERP pass is
-    ``drain.discovery``.  It counts ``upload_bytes`` (every host frame
-    turned into a device array) and ``staged_rows`` (crops whose ERP
-    was uploaded for projection, padding rows included).
+    ``drain.project``, ``drain.forward``, and per chunk
+    ``drain.backproject`` (the launch) and ``drain.fetch`` (the pull);
+    the full-ERP pass is ``drain.discovery``.  It counts
+    ``upload_bytes`` (every host frame turned into a device array),
+    ``staged_rows`` (crops whose ERP was uploaded for projection,
+    padding rows included) and ``backproject_rows`` (rows through the
+    back-projection program, padding rows included).
     """
 
     def __init__(self, variants_cfg, params_per_variant, conf: float = 0.25,
@@ -431,6 +444,10 @@ class JaxDetectorBackend:
         # replica group's mesh, placed once instead of per dispatch
         self._group_params: dict = {}
         self.trace_count = 0  # incremented at trace time only
+        # (pi_box_to_sphbb, padded batch, PI size) -> the jitted
+        # back-projection program, and its own trace counter
+        self._backproject_cache: dict = {}
+        self.backproject_trace_count = 0
         # fused tick: batched gnomonic projection (one dispatch per
         # chunk instead of one `_project` per crop) + a cross-tick crop
         # cache keyed on pitch-quantised region geometry.  `fused=False`
@@ -444,12 +461,21 @@ class JaxDetectorBackend:
 
     def _upload(self, frame_img):
         """``frame_img`` as a device array, its bytes counted when it
-        came from the host."""
+        came from the host.  A host frame goes up flat and one reshape
+        program lays it out on the device.  The device keeps an
+        (H, W, 3) float32 frame channel-planar and tiled, so uploading
+        it as it is makes the host relay it out first, in tens of
+        thousands of small transposes a frame, each an event in a
+        profiler's host trace: enough to run a profiled window out of
+        host memory.  Flat, the host copies it as it is, and the device
+        pays one relayout a frame instead."""
         import jax.numpy as jnp
 
-        if isinstance(frame_img, np.ndarray):
-            self.telemetry.count("upload_bytes", frame_img.nbytes)
-        return jnp.asarray(frame_img)
+        if not isinstance(frame_img, np.ndarray):
+            return jnp.asarray(frame_img)
+        self.telemetry.count("upload_bytes", frame_img.nbytes)
+        flat = np.ascontiguousarray(frame_img).reshape(-1)
+        return jnp.asarray(flat).reshape(frame_img.shape)
 
     def _project(self, frame_img, region: sroi_mod.SRoI, size: int):
         """SRoI -> (size, size, 3) PI; shared by both execution paths
@@ -471,47 +497,78 @@ class JaxDetectorBackend:
                             jnp.asarray(region.center[1]),
                             region.fov, (size, size))
 
-    def _row_to_dets(self, boxes, scores, classes,
-                     region: sroi_mod.SRoI, size: int, geom=None, row=None):
-        """Back-project one row of decoded PI boxes to SphBB detections.
+    def _backproject_fn(self, b_pad: int, size: int):
+        """The jitted back-projection program for one (padded batch,
+        PI size): ``(b_pad, max_det, 4)`` PI boxes and a ``(b_pad, 4)``
+        per-row geometry ``(ct, cp, fov_x, fov_y)`` to ``(b_pad,
+        max_det, 4)`` SphBBs, ``pi_box_to_sphbb`` broadcasting each
+        row's geometry over its detections.  Named
+        ``backproject_s<size>_b<b_pad>``.
 
-        ONE vectorised ``pi_box_to_sphbb`` dispatch over the row's live
-        detections (``pi_box_to_sphbb`` broadcasts over leading axes;
-        bit-identical to the per-detection loop it replaced, pinned by
-        ``tests/test_fused_tick.py``).  ``geom`` overrides the
-        back-projection geometry — a cache hit reuses the PI projected
-        at the anchor region, so its boxes must lift through the anchor
-        geometry, not the (sub-pixel-drifted) query region's.  ``row``
-        picks that row out of batched outputs (sliced on the device, as
-        part of the fetch).
-        """
-        import jax.numpy as jnp
+        ``pi_box_to_sphbb`` is looked up when the program is asked for
+        and is part of the cache key, so a replaced implementation is
+        the one that runs.  The cache and ``backproject_trace_count``
+        are the back-projection's own: ``_jit_cache`` and
+        ``trace_count`` count the forwards alone."""
+        import jax
 
-        tel = self.telemetry
-        with tel.span("drain.fetch"):
-            if row is not None:
-                boxes, scores, classes = boxes[row], scores[row], classes[row]
-            boxes = np.asarray(boxes)
-            scores = np.asarray(scores)
-            classes = np.asarray(classes)
-        live = np.flatnonzero(scores > 0)
-        if live.size == 0:
-            return []
-        with tel.span("drain.backproject"):
-            ct, cp, fov = (geom if geom is not None else
-                           (region.center[0], region.center[1], region.fov))
-            sphbbs = np.asarray(pi_box_to_sphbb(
-                jnp.asarray(boxes[live]), jnp.asarray(ct), jnp.asarray(cp),
-                fov, (size, size)))
-            return [sroi_mod.Detection(box=sphbbs[i],
-                                       category=int(classes[r]),
-                                       score=float(scores[r]))
-                    for i, r in enumerate(live)]
+        impl = pi_box_to_sphbb
+        key = (impl, b_pad, size)
+        fn = self._backproject_cache.get(key)
+        if fn is None:
+            def traced(boxes, geom):
+                self.backproject_trace_count += 1  # trace time only
+                g = geom[:, :, None]  # (b_pad, 4, 1): broadcast per row
+                return impl(boxes, g[:, 0], g[:, 1], (g[:, 2], g[:, 3]),
+                            (size, size))
+
+            traced.__name__ = traced.__qualname__ = (
+                f"backproject_s{size}_b{b_pad}")
+            fn = self._backproject_cache[key] = jax.jit(traced)
+        return fn
+
+    def _launch_backproject(self, boxes, chunk, geoms, size: int):
+        """Queue one chunk's back-projection behind its forward, on the
+        forward's device ``boxes`` as they are.  Each row lifts through
+        ``geoms[r]`` (a cache hit's anchor geometry) or, where that is
+        None, its own region; padding rows repeat the last real row.
+        The geometry goes as an uncommitted host array, so it follows
+        ``boxes`` to whatever devices they are sharded over."""
+        b_pad = boxes.shape[0]
+        rows = [g if g is not None else (region.center[0], region.center[1],
+                                         region.fov)
+                for g, (_, region) in zip(geoms, chunk)]
+        geom = np.array([(ct, cp, fov[0], fov[1])
+                         for ct, cp, fov in _pad_last(rows, b_pad)],
+                        np.float32)
+        with self.telemetry.span("drain.backproject", b=len(chunk),
+                                 padded=b_pad):
+            sphbbs = self._backproject_fn(b_pad, size)(boxes, geom)
+        self.telemetry.count("backproject_rows", b_pad)
+        return sphbbs
+
+    def _fetch_dets(self, n: int, scores, classes, sphbbs) -> list[list]:
+        """ONE device->host pull of a chunk's scores, classes and
+        SphBBs, then its first ``n`` rows' detections on the host: each
+        row's live (``score > 0``) entries in decode order.  Padding
+        rows and zero-score entries were back-projected with the rest
+        of the chunk and are dropped here."""
+        import jax
+
+        with self.telemetry.span("drain.fetch", b=n):
+            scores, classes, sphbbs = jax.device_get(
+                (scores, classes, sphbbs))
+        return [[sroi_mod.Detection(box=sphbbs[r, k],
+                                    category=int(classes[r, k]),
+                                    score=float(scores[r, k]))
+                 for k in np.flatnonzero(scores[r] > 0)]
+                for r in range(n)]
 
     def _forward_one(self, idx: int, img):
         """One (S, S, 3) image through the smallest batch rung's jitted
         program (masked padding rows), so the per-request and discovery
-        paths share the batched path's compiled forwards."""
+        paths share the batched path's compiled forwards.  Returns the
+        padded ``(boxes, scores, classes)``; row 0 is the image's."""
         import jax.numpy as jnp
 
         b_pad = self.buckets.pad_batch(1)
@@ -521,7 +578,7 @@ class JaxDetectorBackend:
                 [imgs, jnp.zeros((b_pad - 1,) + img.shape, img.dtype)])
         boxes, scores, classes, _ = self._batched_fn(idx, b_pad)(
             self.params[idx], imgs, jnp.arange(b_pad) < 1)
-        return boxes[0], scores[0], classes[0]
+        return boxes, scores, classes
 
     def infer_sroi(self, frame_img, region: sroi_mod.SRoI,
                    variant: acc_mod.ModelProfile):
@@ -529,7 +586,9 @@ class JaxDetectorBackend:
         size = self.cfgs[idx].input_size
         pi = self._project(frame_img, region, size)
         boxes, scores, classes = self._forward_one(idx, pi)
-        return self._row_to_dets(boxes, scores, classes, region, size)
+        item = [(frame_img, region)]
+        sphbbs = self._launch_backproject(boxes, item, [None], size)
+        return self._fetch_dets(1, scores, classes, sphbbs)[0]
 
     def _batched_fn(self, idx: int, b_pad: int, group=None):
         """The jitted (apply + masked decode) program for one
@@ -680,7 +739,7 @@ class JaxDetectorBackend:
                 miss.append(i)
             b_proj = self.buckets.pad_batch(len(miss)) if miss else 0
             if miss:
-                sel = miss + [miss[-1]] * (b_proj - len(miss))
+                sel = _pad_last(miss, b_proj)
                 # one upload per projected row: padding rows repeat the
                 # last crop's frame
                 erps = jnp.stack([self._upload(chunk[i][0]) for i in sel])
@@ -716,6 +775,11 @@ class JaxDetectorBackend:
         batched gnomonic dispatch (cache hits skip projection entirely)
         instead of one ``_project`` per crop; ``fused=False`` keeps the
         staged per-crop path as the measured baseline.
+
+        Each chunk's back-projection is launched right behind its
+        forward, so the resolver does one jitted program's worth of
+        waiting and ONE device->host pull per chunk, then builds the
+        rows' detections from NumPy slices.
         """
         import jax.numpy as jnp
 
@@ -723,7 +787,7 @@ class JaxDetectorBackend:
         cfg = self.cfgs[idx]
         size = self.buckets.bucket_resolution(cfg.input_size)
         tel = self.telemetry
-        launched = []  # (chunk, geoms, boxes, scores, classes)
+        launched = []  # (real rows, scores, classes, sphbbs)
         lo = 0
         for b in self.buckets.split(len(items)):
             chunk = items[lo:lo + b]
@@ -749,15 +813,13 @@ class JaxDetectorBackend:
                 boxes, scores, classes, _ = self._batched_fn(
                     idx, b_pad, group)(self._params_for(idx, group), pis,
                                        valid)
-            launched.append((chunk, geoms, boxes, scores, classes))
+            sphbbs = self._launch_backproject(boxes, chunk, geoms, size)
+            launched.append((b, scores, classes, sphbbs))
 
         def resolve() -> list[list]:
             out: list[list] = []
-            for chunk, geoms, boxes, scores, classes in launched:
-                for r, (_, region) in enumerate(chunk):
-                    out.append(self._row_to_dets(
-                        boxes, scores, classes, region, size,
-                        geom=geoms[r], row=r))
+            for n, scores, classes, sphbbs in launched:
+                out.extend(self._fetch_dets(n, scores, classes, sphbbs))
             return out
 
         return resolve
@@ -780,6 +842,7 @@ class JaxDetectorBackend:
 
     def infer_erp(self, frame_img, variant: acc_mod.ModelProfile):
         # ERP-wide pass with the largest model on the resized frame
+        import jax
         import jax.numpy as jnp
 
         from repro.core.projection import resize_erp
@@ -788,11 +851,11 @@ class JaxDetectorBackend:
         size = self.cfgs[idx].input_size
         with self.telemetry.span("drain.discovery", variant=idx):
             resized = resize_erp(self._upload(frame_img), (size, size))
-            boxes, scores, classes = self._forward_one(idx, resized)
+            boxes, scores, classes = jax.device_get(
+                self._forward_one(idx, resized))
             h, w = frame_img.shape[:2]
             dets = []
-            for b, s, c in zip(np.asarray(boxes), np.asarray(scores),
-                               np.asarray(classes)):
+            for b, s, c in zip(boxes[0], scores[0], classes[0]):
                 if s <= 0:
                     continue
                 # rectangular BB on the ERP -> SphBB via ERP coords

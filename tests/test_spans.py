@@ -9,16 +9,22 @@
     uploaded bytes are the staged rows' and discovery's frames;
   * an event log recorded through a span-recording sink is the same,
     byte for byte, as one recorded without it;
-  * each batched forward is a program named for its (variant, bucket).
+  * each batched forward is a program named for its (variant, bucket);
+  * back-projection and the pull are one span each per chunk, and
+    ``backproject_rows`` counts the chunks' padded rows.
 """
 
 import dataclasses
 
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
+from repro.core.sroi import SRoI
 from repro.launch import serve
 from repro.models import detector as det_mod
+from repro.serving import profiles
+from repro.serving.batching import ShapeBuckets
 from repro.serving.fleet import _PodSink
 from repro.serving.replay import CorpusSpec, record
 from repro.serving.telemetry import (JsonlSink, MemorySink, SpanSink,
@@ -156,3 +162,28 @@ def test_forward_program_is_named_per_variant_and_bucket(tiny_pod):
         head = lowered.as_text().splitlines()[0]
         assert f'@"jit_forward_{cfg.name}_b{b_pad}"' in head, head
     assert backend.trace_count == 3
+
+
+@pytest.mark.parametrize("n", [3, 7])
+def test_backproject_and_fetch_are_one_span_per_chunk(tiny_pod, n):
+    """A dispatch of n crops records one ``drain.backproject`` and one
+    ``drain.fetch`` span per chunk, not per row, and counts every row
+    through the back-projection program, padding rows included."""
+    _, backend = serve.build_jax_pod(
+        1, 1, buckets=ShapeBuckets((1, 2, 4), resolutions=(64, 96)))
+    backend.telemetry = sink = SpanSink()
+    frame = np.random.default_rng(0).random((64, 128, 3), np.float32)
+    items = [(frame, SRoI(center=(0.3 * k - 1.0, 0.1), fov=(0.9, 0.7)))
+             for k in range(n)]
+    variant = profiles.make_ladder()[0]
+    out = backend.launch_srois_batched(items, variant)()
+    assert len(out) == n
+
+    chunks = backend.buckets.split(n)
+    padded = [backend.buckets.pad_batch(b) for b in chunks]
+    attrs = {name: [a for s, _, _, _, a in sink.spans if s == name]
+             for name in ("drain.backproject", "drain.fetch")}
+    assert attrs["drain.backproject"] == [
+        {"b": b, "padded": p} for b, p in zip(chunks, padded)]
+    assert attrs["drain.fetch"] == [{"b": b} for b in chunks]
+    assert sink.counters["backproject_rows"] == sum(padded)
